@@ -3,9 +3,10 @@ quantification): CNB recall vs refresh period under profile updates and
 node churn — single-host, plus the same trajectory on a 2-shard mesh
 (recall + estimated wire bytes/epoch vs refresh period).
 
-The distributed cells run in a subprocess: the host device count is fixed
-at jax backend init, so a multi-shard mesh needs its own process with
-XLA_FLAGS set before the first jax import."""
+Every cell runs in a subprocess: the host device count is fixed at jax
+backend init, so a multi-shard mesh needs its own process with XLA_FLAGS
+set before the first jax import, and a parent that had touched jax itself
+would hold the device its children need.  A failed cell raises."""
 
 import dataclasses
 import json
@@ -14,9 +15,25 @@ import subprocess
 import sys
 import time
 
-from repro.core.churn import ChurnConfig, run_churn
+from repro.core.churn import ChurnConfig
 
 N_SHARDS = 2
+
+_STATIC_SCRIPT = r"""
+import json, sys, time
+import dataclasses
+from repro.core.churn import ChurnConfig, run_churn
+
+base = ChurnConfig(**json.loads(sys.argv[1]))
+out = []
+for period in (1, 2, 4, 8):
+    t0 = time.time()
+    r = run_churn(dataclasses.replace(base, refresh_every=period))
+    us = (time.time() - t0) / base.epochs * 1e6
+    out.append(dict(period=period, us=us, mean_recall=r["mean_recall"],
+                    final_recall=r["final_recall"]))
+print("RESULT " + json.dumps(out))
+"""
 
 _DIST_SCRIPT = r"""
 import json, sys, time
@@ -175,35 +192,15 @@ def _failure_rows(base: ChurnConfig):
 
 
 def rows():
-    out = []
     base = ChurnConfig(num_users=2000, epochs=8, num_queries=96,
                        update_rate=0.1, churn_rate=0.03, seed=1)
-    for period in (1, 2, 4, 8):
-        t0 = time.time()
-        r = run_churn(dataclasses.replace(base, refresh_every=period))
-        us = (time.time() - t0) / base.epochs * 1e6
-        out.append((
-            f"churn/refresh_every={period}", us,
-            f"mean_recall={r['mean_recall']:.3f};"
-            f"final_recall={r['final_recall']:.3f}"))
-    try:
-        out.extend(_dist_rows(base))
-    except Exception as e:  # e.g. accelerator jaxlib: the subprocess's
-        # host-platform device flag can't split a GPU/TPU backend — keep
-        # the single-host rows and record the actual failure in the row.
-        reason = " ".join(str(e).split())[:300]
-        out.append((f"churn/dist{N_SHARDS}shard/FAILED", 0.0,
-                    f"{type(e).__name__}: {reason}"))
-    try:
-        out.extend(_node_rows(base))
-    except Exception as e:
-        reason = " ".join(str(e).split())[:300]
-        out.append(("churn/nodes/FAILED", 0.0,
-                    f"{type(e).__name__}: {reason}"))
-    try:
-        out.extend(_failure_rows(base))
-    except Exception as e:
-        reason = " ".join(str(e).split())[:300]
-        out.append(("churn/failure/FAILED", 0.0,
-                    f"{type(e).__name__}: {reason}"))
+    out = [
+        (f"churn/refresh_every={r['period']}", r["us"],
+         f"mean_recall={r['mean_recall']:.3f};"
+         f"final_recall={r['final_recall']:.3f}")
+        for r in _subprocess_rows(_STATIC_SCRIPT, base, 1)
+    ]
+    out.extend(_dist_rows(base))
+    out.extend(_node_rows(base))
+    out.extend(_failure_rows(base))
     return out

@@ -261,17 +261,19 @@ QUERY_PEAKS = {
     # so roofline_frac stays meaningful in CI, not as a precise target
     "cpu": dict(flops=2.0e11, bw=3.0e10),
     "tpu_v5_lite": dict(flops=PEAK_FLOPS, bw=HBM_BW),
-    "*": dict(flops=PEAK_FLOPS, bw=HBM_BW),
 }
 
 
 def _query_peaks(kind: str | None = None) -> dict:
+    """Peaks of this device kind; a kind missing from the table is an
+    error, never another device's numbers."""
     from repro.kernels import autotune
 
     kind = kind or autotune.device_kind()
-    if kind in QUERY_PEAKS:
-        return QUERY_PEAKS[kind]
-    return QUERY_PEAKS["cpu" if kind == "cpu" else "*"]
+    if kind not in QUERY_PEAKS:
+        raise KeyError(f"no roofline peaks for device kind {kind!r}; "
+                       f"known: {sorted(QUERY_PEAKS)}")
+    return QUERY_PEAKS[kind]
 
 
 def query_model(*, r: int, p: int, kc: int, payload_bytes: int, m: int,

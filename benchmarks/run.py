@@ -2,7 +2,8 @@
 
 Prints ``name,us_per_call,derived`` CSV.  `--full` switches in the larger
 LiveJournal/Friendster-scale synthetic datasets (slower); default exercises
-every benchmark at CPU-friendly scale.  `--json PATH` additionally writes
+every benchmark at CPU-friendly scale.  A suite that raises is reported
+on stderr and makes the run exit non-zero once the other suites are done.  `--json PATH` additionally writes
 the same rows as machine-readable JSON (a list of
 ``{"name", "us_per_call", "derived", "suite"}`` objects, e.g.
 ``BENCH_serve.json``), so perf trajectories can be tracked across commits.
@@ -17,6 +18,7 @@ import argparse
 import json
 import sys
 import time
+import traceback
 
 
 def main(argv=None) -> None:
@@ -32,6 +34,9 @@ def main(argv=None) -> None:
                          "(kernels, roofline)")
     args = ap.parse_args(argv)
 
+    from repro import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         bench_churn, bench_distributed, bench_kernels, bench_serve,
         fig1_sp_vs_buckets, fig2_sp_vs_L, fig3_sp_vs_cost, fig4_sp_empirical,
@@ -55,6 +60,7 @@ def main(argv=None) -> None:
     ]
     wanted = [w for w in args.only.split(",") if w]
     collected: list[dict] = []
+    failed: list[str] = []
     print("name,us_per_call,derived")
     for name, fn in suites:
         if wanted and not any(name.startswith(w) for w in wanted):
@@ -76,18 +82,17 @@ def main(argv=None) -> None:
                 collected.append(row)
             print(f"# suite {name} done in {time.time()-t0:.1f}s",
                   file=sys.stderr)
-        except Exception as e:  # keep the harness running
-            print(f"{name}/ERROR,0,{type(e).__name__}:{e}")
-            collected.append(dict(
-                name=f"{name}/ERROR", us_per_call=0.0,
-                derived=f"{type(e).__name__}:{e}", suite=name,
-            ))
+        except Exception:  # run the other suites, then fail the run
+            failed.append(name)
+            traceback.print_exc()
     if args.json:
         with open(args.json, "w") as f:
             json.dump(collected, f, indent=1)
         print(f"# wrote {len(collected)} rows to {args.json}",
               file=sys.stderr)
     sys.stdout.flush()
+    if failed:
+        sys.exit(f"benchmark suites failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -1,15 +1,9 @@
-"""Version-compat shims over jax APIs that moved between releases.
+"""The repo's two mesh primitives, spelled once.
 
-Everything in the repo that builds a mesh or wraps a function in shard_map
-goes through this module, so a single file absorbs the API drift:
-
-  * ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-    ``jax.make_mesh``) only exist in newer jax; older releases build the
-    same (fully ``Auto``) mesh without the kwarg.
-  * ``jax.shard_map`` with ``check_vma=`` is the newer spelling of
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep=``.
-
-Like ``launch.mesh``, importing this module never touches jax device state.
+Everything that builds a mesh or wraps a function in shard_map goes
+through here, so every mesh has all axes ``Auto`` and every shard_map
+states its varying-manual-axes check explicitly.  Like ``launch.mesh``,
+importing this module never touches jax device state.
 """
 
 from __future__ import annotations
@@ -17,38 +11,18 @@ from __future__ import annotations
 import jax
 
 
-def has_axis_type() -> bool:
-    """True when this jax exposes ``jax.sharding.AxisType``."""
-    return hasattr(jax.sharding, "AxisType")
-
-
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with every axis ``Auto``, on any jax version."""
-    if has_axis_type():
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names, devices=devices,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-            )
-        except TypeError:
-            pass  # AxisType exists but make_mesh predates the kwarg
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """Map ``f`` over the mesh shards; ``check`` gates the replication /
-    varying-manual-axes check (named ``check_vma`` or ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check,
-            )
-        except TypeError:
-            pass  # jax.shard_map is public but still spells it check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """Map ``f`` over the mesh shards; ``check`` gates the
+    varying-manual-axes check."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check,
+        check_vma=check,
     )

@@ -86,7 +86,10 @@ def _trajectory(cfg: ChurnConfig):
     events, and query draws.
 
     Yields (epoch, vecs, do_refresh, qidx, ideal); epoch 0 is the initial
-    announce (qidx/ideal None).
+    announce (qidx/ideal None).  Every epoch's `vecs` is a fresh array
+    that is never written again: the CPU backend may alias host arrays
+    without copying, so mutating a yielded array in place would race the
+    device steps still reading it.
     """
     rng = np.random.default_rng(cfg.seed)
     vecs = _unit(rng.standard_normal((cfg.num_users, cfg.dim))).astype(
@@ -95,6 +98,7 @@ def _trajectory(cfg: ChurnConfig):
     yield 0, vecs, True, None, None
 
     for epoch in range(1, cfg.epochs + 1):
+        vecs = vecs.copy()
         # 1. profile updates (vector drift)
         n_upd = int(cfg.update_rate * cfg.num_users)
         upd = rng.choice(cfg.num_users, n_upd, replace=False)

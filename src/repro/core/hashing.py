@@ -45,9 +45,16 @@ def make_hyperplanes(params: LshParams, dtype=jnp.float32) -> jax.Array:
 
     Gaussian entries make each row a uniformly random hyperplane normal,
     which is exactly the Goemans-Williamson rounding construction.
+
+    The draw pins the non-partitionable threefry stream: the hyperplanes
+    are the index's hash function, so a seed must name the same planes
+    on every jax release (newer releases default to the partitionable
+    stream, which would re-bucket every stored sketch).
     """
     key = jax.random.PRNGKey(params.seed)
-    return jax.random.normal(key, (params.L, params.k, params.d), dtype=dtype)
+    with jax.threefry_partitionable(False):
+        return jax.random.normal(
+            key, (params.L, params.k, params.d), dtype=dtype)
 
 
 def normalize(x: jax.Array, axis: int = -1, eps: float = 1e-12) -> jax.Array:

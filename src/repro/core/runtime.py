@@ -1362,6 +1362,8 @@ class IndexRuntime:
         )
 
     def make_insert_step(self):
+        """Insert/refresh step; donates the store on every topology (the
+        payload is the largest buffer, and the old generation is dead)."""
         if self.mesh is None:
             cfg = self.cfg
 
@@ -1369,7 +1371,8 @@ class IndexRuntime:
                 return insert_kernel(cfg, LOCAL, hyperplanes, st, vec, vid,
                                      now)
 
-            return self._step("insert", lambda: jax.jit(fn))
+            return self._step(
+                "insert", lambda: jax.jit(fn, donate_argnums=(1,)))
         return self._step(
             "insert",
             lambda: self._dist().make_insert_step(

@@ -102,7 +102,10 @@ def score_topk(
         scores = jnp.where(cand_ids >= 0, -h.astype(jnp.float32), NEG_INF)
         return dedupe_topk(cand_ids, scores, m)
     if not use_kernels:
-        scores = jnp.einsum("bkd,bd->bk", cand_vecs, q)
+        # full f32 products (the TPU's default would round to bf16): the
+        # fused kernel scores in f32 too, so the two paths rank alike
+        scores = jnp.einsum("bkd,bd->bk", cand_vecs, q,
+                            precision=jax.lax.Precision.HIGHEST)
         scores = jnp.where(cand_ids >= 0, scores, NEG_INF)
         return dedupe_topk(cand_ids, scores, m)
     return _score_topk_kernel(q, cand_ids, cand_vecs, m, interpret)

@@ -6,7 +6,7 @@ MXU):
   * `hamming_pallas` — single-word codes ([n] vs [n, kc]), used by
     ranked multi-probe planning and Layered-LSH node assignment;
   * `hamming_words_pallas` — multi-word packed rows ([n, W] vs
-    [n, kc, W], the `core.packed` layout), the staged scoring primitive
+    word-major [n, W, kc] candidates), the staged scoring primitive
     of `score="hamming"` runtimes; the fused query kernel inlines the
     same popcount for its hamming mode.
 
@@ -59,31 +59,33 @@ def hamming_pallas(
 
 
 def _hamming_words_kernel(codes_ref, cand_ref, out_ref):
-    codes = codes_ref[...]  # [TN, 1, W] uint32
-    cand = cand_ref[...]    # [TN, KC, W] uint32
+    codes = codes_ref[...]  # [TN, W, 1] uint32
+    cand = cand_ref[...]    # [TN, W, KC] uint32 (word-major)
     out_ref[...] = jnp.sum(
-        _popcount32(jnp.bitwise_xor(codes, cand)), axis=-1
+        _popcount32(jnp.bitwise_xor(codes, cand)), axis=1
     )
 
 
 @functools.partial(jax.jit, static_argnames=("tn", "interpret"))
 def hamming_words_pallas(
     codes: jax.Array,       # [n, W] uint32 packed words (n % tn == 0)
-    cand_codes: jax.Array,  # [n, kc, W] uint32 (kc % 128 == 0)
+    cand_codes: jax.Array,  # [n, W, kc] uint32, word-major (kc % 128 == 0)
     *,
-    tn: int = 256,
+    tn: int = 16,
     interpret: bool = False,
 ) -> jax.Array:
-    n, kc, w = cand_codes.shape
+    """Word-major candidates keep the candidate axis on the TPU's lanes
+    (a [.., kc, W] block would pad W up to 128 lanes in VMEM)."""
+    n, w, kc = cand_codes.shape
     grid = (n // tn,)
     return pl.pallas_call(
         _hamming_words_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tn, 1, w), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tn, kc, w), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tn, w, 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tn, w, kc), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((tn, kc), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, kc), jnp.int32),
         interpret=interpret,
-    )(codes[:, None, :], cand_codes)
+    )(codes[:, :, None], cand_codes)

@@ -36,20 +36,6 @@ def _pad_to(x: jax.Array, axis: int, multiple: int, value=0) -> jax.Array:
     return jnp.pad(x, widths, constant_values=value)
 
 
-def _pack_bits(bits: jax.Array) -> jax.Array:
-    """bool/int [..., n] (n % 32 == 0) -> uint32 bitfield words [..., n/32].
-
-    Kernel-side validity layout only (little-endian, bit i of word w =
-    lane w*32 + i); the canonical sketch-code packing lives in
-    `core.packed` — this tiny twin exists so kernels/ has no import edge
-    into core/.
-    """
-    *lead, n = bits.shape
-    grouped = bits.reshape(*lead, n // 32, 32).astype(jnp.uint32)
-    weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
-    return jnp.sum(grouped * weights, axis=-1, dtype=jnp.uint32)
-
-
 def simhash(
     x: jax.Array,            # [n, d] float
     hyperplanes: jax.Array,  # [L, k, d] float
@@ -96,20 +82,20 @@ def bucket_topk(
     interpret: bool | None = None,
 ):
     """Fused score + top-m. Returns (scores [b, m] f32, idx [b, m] i32).
-    Matches `ref.bucket_topk_ref` (ties -> lowest index).  Validity
-    travels as packed uint32 bitfield words (32x less mask traffic than
-    the old int8 lanes); the kernel unpacks bits in-register."""
+    Matches `ref.bucket_topk_ref` (ties -> lowest index).  The kernel
+    walks kc in 128-lane chunks with a running top m, so its VMEM use
+    does not grow with the candidate count."""
     interpret = _on_cpu() if interpret is None else interpret
     b, kc, d = cand.shape
     tb_eff = min(tb, max(1, b))
     q_p = _pad_to(q.astype(jnp.float32), 0, tb_eff)
     cand_p = _pad_to(cand.astype(jnp.float32), 0, tb_eff)
-    valid_p = _pad_to(valid.astype(jnp.int8), 0, tb_eff)
+    valid_p = _pad_to(valid.astype(jnp.int32), 0, tb_eff)
     q_p = _pad_to(q_p, 1, LANE)
     cand_p = _pad_to(_pad_to(cand_p, 2, LANE), 1, LANE)
     valid_p = _pad_to(valid_p, 1, LANE)
     s, i = _bt.bucket_topk_pallas(
-        q_p, cand_p, _pack_bits(valid_p), m=m, tb=tb_eff, interpret=interpret
+        q_p, cand_p, valid_p, m=m, tb=tb_eff, tkc=LANE, interpret=interpret
     )
     return s[:b], i[:b]
 
@@ -118,7 +104,6 @@ def hamming(
     codes: jax.Array,       # [n] uint32 or [n, W] packed words
     cand_codes: jax.Array,  # [n, kc] uint32 or [n, kc, W] packed words
     *,
-    tn: int = 256,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Hamming distances int32 [n, kc].
@@ -127,20 +112,23 @@ def hamming(
     multi-word packed rows ([n, W] vs [n, kc, W], the `core.packed`
     layout) match `ref.hamming_words_ref` — this shape is the staged
     scoring primitive of `score="hamming"` runtimes.  Padded candidate
-    columns return distance vs code 0 and are sliced off."""
+    columns return distance vs code 0 and are sliced off.  The row tiles
+    keep each kernel's VMEM blocks far under the TPU's scoped limit at
+    P*C = 1920 candidates per row."""
     interpret = _on_cpu() if interpret is None else interpret
     if cand_codes.ndim == 3:
         n, kc, w = cand_codes.shape
-        tn_eff = min(tn, max(8, n))
+        tn_eff = min(16, max(8, n))
         codes_p = _pad_to(codes.astype(jnp.uint32), 0, tn_eff)
-        cand_p = _pad_to(cand_codes.astype(jnp.uint32), 0, tn_eff)
-        cand_p = _pad_to(cand_p, 1, LANE if not interpret else 8)
+        cand_p = jnp.swapaxes(cand_codes.astype(jnp.uint32), 1, 2)
+        cand_p = _pad_to(_pad_to(cand_p, 0, tn_eff),
+                         2, LANE if not interpret else 8)
         out = _hm.hamming_words_pallas(
             codes_p, cand_p, tn=tn_eff, interpret=interpret
         )
         return out[:n, :kc]
     n, kc = cand_codes.shape
-    tn_eff = min(tn, max(8, n))
+    tn_eff = min(256, max(8, n))
     codes_p = _pad_to(codes.astype(jnp.uint32), 0, tn_eff)
     cand_p = _pad_to(cand_codes.astype(jnp.uint32), 0, tn_eff)
     cand_p = _pad_to(cand_p, 1, LANE)
@@ -179,13 +167,16 @@ def fused_query(
     r, _ = fb.shape
     c = ids_flat.shape[-1]
     kc_eff = min(kc, max(8, c)) if interpret else kc
-    ids_p = _pad_to(ids_flat.astype(jnp.int32), 1, kc_eff, value=-1)
+    ids_p = _pad_to(_pad_to(ids_flat.astype(jnp.int32), 1, kc_eff, value=-1),
+                    0, _fq.SLAB, value=-1)
     pay_p = _pad_to(pay_flat, 1, kc_eff)
     if score == "dot":
         pay_p = _pad_to(pay_p.astype(jnp.float32), 2, 8 if interpret else LANE)
         q_p = _pad_to(q.astype(jnp.float32), 1, 8 if interpret else LANE)
     else:
-        pay_p = pay_p.astype(jnp.uint32)
+        # word-major view: the TPU keeps a [.., C, W] uint32 store with
+        # C as its minor (lane) axis, so this swap moves no bytes there
+        pay_p = jnp.swapaxes(pay_p.astype(jnp.uint32), 1, 2)
         q_p = q.astype(jnp.uint32)
     tb_eff = min(tb, max(1, r))
     fb_p = jnp.clip(
@@ -194,7 +185,7 @@ def fused_query(
     meta_p = _pad_to(meta.astype(jnp.int32), 0, tb_eff)  # pad: pword 0
     ids_r, sc_r = _fq.fused_query_pallas(
         ids_p, pay_p, _pad_to(q_p, 0, tb_eff), fb_p, meta_p,
-        m=m, tb=tb_eff, kc=ids_p.shape[-1], score=score, interpret=interpret,
+        m=m, tb=tb_eff, score=score, interpret=interpret,
     )
     return ids_r[:r], sc_r[:r]
 
@@ -216,7 +207,8 @@ def fused_contains(
     r, _ = fb.shape
     c = ids_flat.shape[-1]
     kc_eff = min(8, max(1, c)) if interpret else LANE
-    ids_p = _pad_to(ids_flat.astype(jnp.int32), 1, kc_eff, value=-1)
+    ids_p = _pad_to(_pad_to(ids_flat.astype(jnp.int32), 1, kc_eff, value=-1),
+                    0, _fq.SLAB, value=-1)
     tb_eff = min(tb, max(1, r))
     fb_p = jnp.clip(
         _pad_to(fb.astype(jnp.int32), 0, tb_eff), 0, ids_p.shape[0] - 1

@@ -7,6 +7,7 @@ by lowest index in both ref and kernel.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -43,7 +44,8 @@ def bucket_topk_ref(
       candidate; sorted by descending score, ties -> lowest index.
     """
     scores = jnp.einsum(
-        "bd,bkd->bk", q.astype(jnp.float32), cand.astype(jnp.float32)
+        "bd,bkd->bk", q.astype(jnp.float32), cand.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
     )
     scores = jnp.where(valid, scores, -jnp.inf)
     kc = scores.shape[1]
@@ -132,7 +134,8 @@ def fused_query_ref(
     pay = jnp.take(pay_flat, fb, axis=0)                   # [r, P, KC, DW]
     if score == "dot":
         s = jnp.einsum(
-            "rd,rpkd->rpk", q.astype(jnp.float32), pay.astype(jnp.float32)
+            "rd,rpkd->rpk", q.astype(jnp.float32), pay.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
         )
     elif score == "hamming":
         s = -hamming_words_ref(

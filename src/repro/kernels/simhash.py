@@ -51,32 +51,34 @@ def _simhash_kernel(
 
     @pl.when(d_step == n_dsteps - 1)
     def _pack():
+        # Bits are packed in int32 (the TPU reduces no unsigned type):
+        # each word sums distinct powers of two, so the two's-complement
+        # sum IS the bit pattern, reinterpreted as uint32 on the way out.
         proj = acc_ref[...]  # [TN, LKpad]
-        bits = (proj >= 0).astype(jnp.uint32)  # [TN, LKpad]
+        bits = (proj >= 0).astype(jnp.int32)  # [TN, LKpad]
         # lane l*k + j holds bit j of table l.
         lane = jax.lax.broadcasted_iota(jnp.int32, proj.shape, 1)
         if packed:
             # dense core.packed layout: lane g -> word g/32 bit g%32.
             # padded tail lanes (proj 0 => bit 1) must be masked here.
             n_words = -(-(L * k) // 32)
-            live = jnp.where(lane < L * k, bits, jnp.uint32(0))
-            shifted = live << (lane % 32).astype(jnp.uint32)
+            live = jnp.where(lane < L * k, bits, 0)
+            shifted = live << (lane % 32)
             words = [
-                jnp.sum(jnp.where(lane // 32 == w, shifted, jnp.uint32(0)),
-                        axis=1)
+                jnp.sum(jnp.where(lane // 32 == w, shifted, 0), axis=1)
                 for w in range(n_words)
             ]
-            out_ref[...] = jnp.stack(words, axis=1)
         else:
             # (lane % k) is the in-code bit position; padded tail lanes
             # (>= L*k) are never sliced below.
-            weighted = bits << (lane % k).astype(jnp.uint32)
+            weighted = bits << (lane % k)
             # per-table static slices + lane reduction (no in-kernel scatter)
-            codes = [
+            words = [
                 jnp.sum(weighted[:, l * k : (l + 1) * k], axis=1)
                 for l in range(L)
             ]
-            out_ref[...] = jnp.stack(codes, axis=1)
+        out_ref[...] = jax.lax.bitcast_convert_type(
+            jnp.stack(words, axis=1), jnp.uint32)
 
 
 @functools.partial(

@@ -2,8 +2,8 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (the dry-run must set XLA_FLAGS before any jax initialization).
-Mesh construction goes through `repro.compat` so the same code runs on
-jax versions with and without `jax.sharding.AxisType`.
+Mesh construction goes through `repro.compat`, which builds every mesh
+with all axes `Auto`.
 """
 
 from __future__ import annotations

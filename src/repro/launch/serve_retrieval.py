@@ -39,6 +39,7 @@ import argparse
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (
     DenseCorpus, EngineConfig, LshEngine, LshParams, make_hyperplanes,
 )
@@ -286,6 +287,7 @@ def main(argv=None):
                     help="shadow-rescore every Nth served miss against "
                          "the exact top-m (0 = off; needs obs enabled)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.smoke:
         args.n, args.d, args.k = 2000, 32, 6
