@@ -3,10 +3,14 @@ quantification): CNB recall vs refresh period under profile updates and
 node churn — single-host, plus the same trajectory on a 2-shard mesh
 (recall + estimated wire bytes/epoch vs refresh period).
 
-Every cell runs in a subprocess: the host device count is fixed at jax
-backend init, so a multi-shard mesh needs its own process with XLA_FLAGS
-set before the first jax import, and a parent that had touched jax itself
-would hold the device its children need.  A failed cell raises."""
+The single-host cells run in this process, on the default backend.  The
+mesh cells (dist, nodes, failure) need several devices of one kind, which
+a one-chip host has only as forced host (CPU) devices: each runs in a
+child pinned to `JAX_PLATFORMS=cpu` with XLA_FLAGS set before its first
+jax import (the host device count is fixed at backend init).  Those
+children never open the accelerator, so they cannot contend with this
+process for it.  Their columns are recall and modelled wire bytes; their
+`us` is CPU time.  A failed cell raises."""
 
 import dataclasses
 import json
@@ -15,25 +19,9 @@ import subprocess
 import sys
 import time
 
-from repro.core.churn import ChurnConfig
-
-N_SHARDS = 2
-
-_STATIC_SCRIPT = r"""
-import json, sys, time
-import dataclasses
 from repro.core.churn import ChurnConfig, run_churn
 
-base = ChurnConfig(**json.loads(sys.argv[1]))
-out = []
-for period in (1, 2, 4, 8):
-    t0 = time.time()
-    r = run_churn(dataclasses.replace(base, refresh_every=period))
-    us = (time.time() - t0) / base.epochs * 1e6
-    out.append(dict(period=period, us=us, mean_recall=r["mean_recall"],
-                    final_recall=r["final_recall"]))
-print("RESULT " + json.dumps(out))
-"""
+N_SHARDS = 2
 
 _DIST_SCRIPT = r"""
 import json, sys, time
@@ -127,6 +115,7 @@ N_NODES_MAX = 4
 def _subprocess_rows(script: str, base: ChurnConfig, devices: int,
                      extra_args=()):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}"
     )
@@ -194,12 +183,14 @@ def _failure_rows(base: ChurnConfig):
 def rows():
     base = ChurnConfig(num_users=2000, epochs=8, num_queries=96,
                        update_rate=0.1, churn_rate=0.03, seed=1)
-    out = [
-        (f"churn/refresh_every={r['period']}", r["us"],
-         f"mean_recall={r['mean_recall']:.3f};"
-         f"final_recall={r['final_recall']:.3f}")
-        for r in _subprocess_rows(_STATIC_SCRIPT, base, 1)
-    ]
+    out = []
+    for period in (1, 2, 4, 8):
+        t0 = time.time()
+        r = run_churn(dataclasses.replace(base, refresh_every=period))
+        us = (time.time() - t0) / base.epochs * 1e6
+        out.append((f"churn/refresh_every={period}", us,
+                    f"mean_recall={r['mean_recall']:.3f};"
+                    f"final_recall={r['final_recall']:.3f}"))
     out.extend(_dist_rows(base))
     out.extend(_node_rows(base))
     out.extend(_failure_rows(base))

@@ -11,7 +11,7 @@ beats a full sort by a wide margin.
 
 Tiling: grid (b/TB, KC/TKC).  KC (candidates per query = L * probes *
 capacity, gathered by the caller) is lane-padded to 128 and walked in
-TKC-wide chunks; a [TB, m] VMEM carry holds the running top m, which
+TKC = 128-wide chunks; a [TB, m] VMEM carry holds the running top m, which
 each chunk's selection merges with its own candidates.  VMEM use is
 therefore independent of KC.  Invalid slots carry validity 0 and return
 score=-inf, idx=-1.
@@ -28,6 +28,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG = float("-inf")  # plain Python float: jnp constants can't be captured by kernels
 IMAX = 2**31 - 1
+TKC = 128  # candidates per grid step: one lane width
 
 
 def _topk_kernel(q_ref, cand_ref, valid_ref, s_ref, i_ref, run_s, run_i,
@@ -77,26 +78,25 @@ def _topk_kernel(q_ref, cand_ref, valid_ref, s_ref, i_ref, run_s, run_i,
         i_ref[...] = out_i
 
 
-@functools.partial(jax.jit, static_argnames=("m", "tb", "tkc", "interpret"))
+@functools.partial(jax.jit, static_argnames=("m", "tb", "interpret"))
 def bucket_topk_pallas(
     q: jax.Array,       # [b, d] float32   (b % tb == 0, d lane-padded)
-    cand: jax.Array,    # [b, kc, d] float32 (kc % tkc == 0)
+    cand: jax.Array,    # [b, kc, d] float32 (kc % TKC == 0)
     valid: jax.Array,   # int32 [b, kc] (nonzero = valid candidate)
     *,
     m: int,
     tb: int = 8,
-    tkc: int = 128,
     interpret: bool = False,
 ):
     b, kc, d = cand.shape
-    grid = (b // tb, kc // tkc)
+    grid = (b // tb, kc // TKC)
     return pl.pallas_call(
         functools.partial(_topk_kernel, m=m),
         grid=grid,
         in_specs=[
             pl.BlockSpec((tb, d), lambda i, c: (i, 0)),
-            pl.BlockSpec((tb, tkc, d), lambda i, c: (i, c, 0)),
-            pl.BlockSpec((tb, tkc), lambda i, c: (i, c)),
+            pl.BlockSpec((tb, TKC, d), lambda i, c: (i, c, 0)),
+            pl.BlockSpec((tb, TKC), lambda i, c: (i, c)),
         ],
         out_specs=[
             pl.BlockSpec((tb, m), lambda i, c: (i, 0)),

@@ -92,10 +92,10 @@ def bucket_topk(
     cand_p = _pad_to(cand.astype(jnp.float32), 0, tb_eff)
     valid_p = _pad_to(valid.astype(jnp.int32), 0, tb_eff)
     q_p = _pad_to(q_p, 1, LANE)
-    cand_p = _pad_to(_pad_to(cand_p, 2, LANE), 1, LANE)
-    valid_p = _pad_to(valid_p, 1, LANE)
+    cand_p = _pad_to(_pad_to(cand_p, 2, LANE), 1, _bt.TKC)
+    valid_p = _pad_to(valid_p, 1, _bt.TKC)
     s, i = _bt.bucket_topk_pallas(
-        q_p, cand_p, valid_p, m=m, tb=tb_eff, tkc=LANE, interpret=interpret
+        q_p, cand_p, valid_p, m=m, tb=tb_eff, interpret=interpret
     )
     return s[:b], i[:b]
 
@@ -120,6 +120,9 @@ def hamming(
         n, kc, w = cand_codes.shape
         tn_eff = min(16, max(8, n))
         codes_p = _pad_to(codes.astype(jnp.uint32), 0, tn_eff)
+        # word-major for the kernel: unlike the fused path's view of the
+        # store, this swaps a freshly gathered tensor, which may cost a
+        # relayout per call
         cand_p = jnp.swapaxes(cand_codes.astype(jnp.uint32), 1, 2)
         cand_p = _pad_to(_pad_to(cand_p, 0, tn_eff),
                          2, LANE if not interpret else 8)
