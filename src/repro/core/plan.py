@@ -109,7 +109,8 @@ def make_plan(
     """
     k = spec.params.k
     topo = topology or CanTopology(k, 1 << k)  # paper: one bucket per node
-    codes = sketch(q, hyperplanes, use_kernels=use_kernels)  # [..., L]
+    with jax.named_scope("sketch"):
+        codes = sketch(q, hyperplanes, use_kernels=use_kernels)  # [..., L]
 
     p = spec.near_probes
     full_mask = jnp.uint32((1 << k) - 1)

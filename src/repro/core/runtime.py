@@ -404,17 +404,20 @@ def _fused_search_local(
         t, nb, c = store_ids.shape
         ids_flat = store_ids.reshape(t * nb, c)
         pay_flat = store_payload.reshape(t * nb, c, store_payload.shape[-1])
-        fb, pword = _fused_probe_rows(cfg, nb, table, local_idx, mask)
+        with jax.named_scope("probe_plan"):
+            fb, pword = _fused_probe_rows(cfg, nb, table, local_idx, mask)
     else:
-        all_ids = jnp.concatenate(
-            [store_ids[:, None], rep_ids], axis=1)         # [T, R, NB, C]
-        all_pay = jnp.concatenate(
-            [store_payload[:, None], rep_payload], axis=1)
+        with jax.named_scope("payload_prep"):
+            all_ids = jnp.concatenate(
+                [store_ids[:, None], rep_ids], axis=1)     # [T, R, NB, C]
+            all_pay = jnp.concatenate(
+                [store_payload[:, None], rep_payload], axis=1)
         t, n_rep, nb, c = all_ids.shape
         ids_flat = all_ids.reshape(t * n_rep * nb, c)
         pay_flat = all_pay.reshape(t * n_rep * nb, c, all_pay.shape[-1])
-        fb, pword = _fused_probe_rows(cfg, nb, table, local_idx, mask,
-                                      rep_sel=rep_sel, n_rep=n_rep)
+        with jax.named_scope("probe_plan"):
+            fb, pword = _fused_probe_rows(cfg, nb, table, local_idx, mask,
+                                          rep_sel=rep_sel, n_rep=n_rep)
     excl = (
         jnp.full_like(pword, -1) if exclude is None
         else exclude.astype(jnp.int32)
@@ -735,8 +738,12 @@ def search_kernel(
     L = cfg.params.L
     n = cx.n
     b_loc = q.shape[0]
-    plan, flat = _flat_plan(cfg, cx, q, hyperplanes)
-    probes = _probes_issued(flat["mask"])
+    # named scopes mark the step's stages in the device trace's op
+    # metadata: probe_plan (holding sketch), payload_prep, fused_kernel
+    # (kernels/ops.py) and merge
+    with jax.named_scope("probe_plan"):
+        plan, flat = _flat_plan(cfg, cx, q, hyperplanes)
+        probes = _probes_issued(flat["mask"])
 
     qs = q
     if cfg.score == "hamming":
@@ -766,9 +773,10 @@ def search_kernel(
                 qs[flat["qidx"]], flat["table"], flat["local"],
                 flat["mask"], ex, m,
             )                                              # [b_loc*L, m]
-        ids, sc = dedupe_topk(
-            ids_r.reshape(b_loc, L * m), sc_r.reshape(b_loc, L * m), m
-        )
+        with jax.named_scope("merge"):
+            ids, sc = dedupe_topk(
+                ids_r.reshape(b_loc, L * m), sc_r.reshape(b_loc, L * m), m
+            )
         return ids, sc, StepStats.local(n, probes, b_loc)
 
     if cfg.routing == "allgather":

@@ -170,26 +170,31 @@ def fused_query(
     r, _ = fb.shape
     c = ids_flat.shape[-1]
     kc_eff = min(kc, max(8, c)) if interpret else kc
-    ids_p = _pad_to(_pad_to(ids_flat.astype(jnp.int32), 1, kc_eff, value=-1),
-                    0, _fq.SLAB, value=-1)
-    pay_p = _pad_to(pay_flat, 1, kc_eff)
-    if score == "dot":
-        pay_p = _pad_to(pay_p.astype(jnp.float32), 2, 8 if interpret else LANE)
-        q_p = _pad_to(q.astype(jnp.float32), 1, 8 if interpret else LANE)
-    else:
-        # word-major view: the TPU keeps a [.., C, W] uint32 store with
-        # C as its minor (lane) axis, so this swap moves no bytes there
-        pay_p = jnp.swapaxes(pay_p.astype(jnp.uint32), 1, 2)
-        q_p = q.astype(jnp.uint32)
     tb_eff = min(tb, max(1, r))
-    fb_p = jnp.clip(
-        _pad_to(fb.astype(jnp.int32), 0, tb_eff), 0, ids_p.shape[0] - 1
-    )
-    meta_p = _pad_to(meta.astype(jnp.int32), 0, tb_eff)  # pad: pword 0
-    ids_r, sc_r = _fq.fused_query_pallas(
-        ids_p, pay_p, _pad_to(q_p, 0, tb_eff), fb_p, meta_p,
-        m=m, tb=tb_eff, score=score, interpret=interpret,
-    )
+    with jax.named_scope("payload_prep"):
+        ids_p = _pad_to(
+            _pad_to(ids_flat.astype(jnp.int32), 1, kc_eff, value=-1),
+            0, _fq.SLAB, value=-1)
+        pay_p = _pad_to(pay_flat, 1, kc_eff)
+        if score == "dot":
+            pay_p = _pad_to(pay_p.astype(jnp.float32), 2,
+                            8 if interpret else LANE)
+            q_p = _pad_to(q.astype(jnp.float32), 1, 8 if interpret else LANE)
+        else:
+            # word-major view: the TPU keeps a [.., C, W] uint32 store with
+            # C as its minor (lane) axis, so this swap moves no bytes there
+            pay_p = jnp.swapaxes(pay_p.astype(jnp.uint32), 1, 2)
+            q_p = q.astype(jnp.uint32)
+        fb_p = jnp.clip(
+            _pad_to(fb.astype(jnp.int32), 0, tb_eff), 0, ids_p.shape[0] - 1
+        )
+        meta_p = _pad_to(meta.astype(jnp.int32), 0, tb_eff)  # pad: pword 0
+        q_p = _pad_to(q_p, 0, tb_eff)
+    with jax.named_scope("fused_kernel"):
+        ids_r, sc_r = _fq.fused_query_pallas(
+            ids_p, pay_p, q_p, fb_p, meta_p,
+            m=m, tb=tb_eff, score=score, interpret=interpret,
+        )
     return ids_r[:r], sc_r[:r]
 
 

@@ -3,15 +3,17 @@
 Three small host-side pieces, shared by serving, the churn drivers, and
 the benchmarks:
 
-  * `registry` — labeled counters / gauges / histograms with JSON and
-    Prometheus-text snapshots.  THE sink every producer publishes into
+  * `registry` — labeled counters / gauges / histograms with JSON
+    snapshots.  THE sink every producer publishes into
     (`ServeStats.publish`, `MessageCounter.publish`, churn drivers),
     replacing the per-subsystem ad-hoc dict formats as the machine
     interface;
-  * `trace` — a span API on `time.perf_counter` (monotonic — the repo's
-    one timer, also used by the launch drivers and benchmarks for their
-    wall-clock numbers), exportable as Chrome-trace-event JSON that
-    loads directly in Perfetto / chrome://tracing;
+  * `trace` — spans that are `jax.profiler` annotations (on the device
+    trace's clock whenever a profiler session is on), timed on
+    `time.perf_counter` (monotonic — the repo's one timer, also used by
+    the launch drivers and benchmarks for their wall-clock numbers) and,
+    with a `Tracer`, kept in a ring exportable as Chrome-trace-event
+    JSON that loads directly in Perfetto / chrome://tracing;
   * `flight` — a bounded ring of structured per-query / per-dispatch
     `QueryRecord`s, dumped automatically on anomalies (drop spike,
     `kill_node`, reshard) so the records AROUND a failure survive it.

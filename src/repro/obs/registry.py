@@ -2,12 +2,9 @@
 Sec. 12).
 
 One `Registry` holds named metrics; each metric holds one series per
-label set (labels are plain keyword arguments).  Two export formats:
-
-  * `snapshot()` — a JSON-able dict (what `--metrics-out` writes and
-    `benchmarks/run.py --json` reads columns from);
-  * `prometheus_text()` — the Prometheus text exposition format, so a
-    scrape endpoint needs nothing beyond serving this string.
+label set (labels are plain keyword arguments).  `snapshot()` exports a
+JSON-able dict (what `--metrics-out` writes and `benchmarks/run.py
+--json` reads columns from).
 
 Registration is idempotent: asking for an existing name returns the same
 metric object (re-registering under a different kind is an error), so
@@ -32,11 +29,6 @@ DEFAULT_BUCKETS = (
 
 def _label_key(labels: dict) -> tuple:
     return tuple(sorted((str(k), str(v)) for k, v in labels.items()))
-
-
-def _label_str(key: tuple, extra: tuple = ()) -> str:
-    parts = [f'{k}="{v}"' for k, v in (*key, *extra)]
-    return "{" + ",".join(parts) + "}" if parts else ""
 
 
 class _Metric:
@@ -198,7 +190,7 @@ class Registry:
     def reset(self) -> None:
         self._metrics.clear()
 
-    # -- exports --------------------------------------------------------------
+    # -- export ---------------------------------------------------------------
 
     def snapshot(self) -> dict:
         """JSON-able snapshot: {name: {type, help, samples: [...]}}.
@@ -224,31 +216,6 @@ class Registry:
                     samples.append(dict(labels=labels, value=st))
             out[name] = dict(type=m.kind, help=m.help, samples=samples)
         return out
-
-    def prometheus_text(self) -> str:
-        """Prometheus text exposition format (one scrape body)."""
-        lines = []
-        for name, m in sorted(self._metrics.items()):
-            if m.help:
-                lines.append(f"# HELP {name} {m.help}")
-            lines.append(f"# TYPE {name} {m.kind}")
-            for key, st in sorted(m._series.items()):
-                if m.kind == "histogram":
-                    cum = 0
-                    for i, c in enumerate(st["counts"]):
-                        cum += c
-                        edge = (f"{m.buckets[i]:g}"
-                                if i < len(m.buckets) else "+Inf")
-                        lines.append(
-                            f"{name}_bucket"
-                            f"{_label_str(key, (('le', edge),))} {cum}"
-                        )
-                    lines.append(f"{name}_sum{_label_str(key)} {st['sum']:g}")
-                    lines.append(
-                        f"{name}_count{_label_str(key)} {st['count']}")
-                else:
-                    lines.append(f"{name}{_label_str(key)} {st:g}")
-        return "\n".join(lines) + ("\n" if lines else "")
 
 
 # the process-default registry: CLIs and benchmarks publish here unless

@@ -118,19 +118,23 @@ class PendingDispatch:
     JAX async dispatch means `RuntimeBackend.dispatch_async` returns one
     of these BEFORE the batch computes; `ready()` is a non-blocking
     completion probe and `wait()` is the only place a device sync
-    happens — it blocks, converts to host arrays, and (on a mesh
-    backend) applies the host-side self-exclusion.  The exclusion row
-    and m are captured at dispatch time, so a backend update installed
-    while the batch is in flight cannot change how it finishes."""
+    happens — it blocks (`serve/compute`), converts to host arrays and
+    (on a mesh backend) applies the host-side self-exclusion
+    (`serve/fetch`), both spans tagged with the dispatch's `batch`.  The
+    exclusion row and m are captured at dispatch time, so a backend
+    update installed while the batch is in flight cannot change how it
+    finishes."""
 
-    __slots__ = ("_backend", "_raw", "_ex", "_m", "_distributed", "_done")
+    __slots__ = ("_backend", "_raw", "_ex", "_m", "_distributed", "_done",
+                 "_batch")
 
-    def __init__(self, backend, raw, ex_pad, m, distributed):
+    def __init__(self, backend, raw, ex_pad, m, distributed, batch):
         self._backend = backend
         self._raw = raw
         self._ex = ex_pad
         self._m = m
         self._distributed = distributed
+        self._batch = batch
         self._done = None
 
     def ready(self) -> bool:
@@ -142,11 +146,13 @@ class PendingDispatch:
     def wait(self):
         """Block until complete; returns (ids, scores, stats) host-side."""
         if self._done is None:
-            with span_or_null(self._backend.tracer, "serve/compute"):
+            tr = self._backend.tracer
+            with span_or_null(tr, "serve/compute", batch=self._batch):
                 jax.block_until_ready(self._raw)
-            self._done = self._backend._finish(
-                self._raw, self._ex, self._m, self._distributed
-            )
+            with span_or_null(tr, "serve/fetch", batch=self._batch):
+                self._done = self._backend._finish(
+                    self._raw, self._ex, self._m, self._distributed
+                )
             self._raw = None  # drop the device handles
         return self._done
 
@@ -242,8 +248,11 @@ class RuntimeBackend:
             step = runtime.search_step_fn()
 
             def _impl(hp, *args):
-                self.traces += 1  # runs at trace time only
-                return step(hp, *args)
+                # runs at trace time only
+                with span_or_null(self.tracer, "serve/retrace",
+                                  pad=args[-1].shape[0]):
+                    self.traces += 1
+                    return step(hp, *args)
 
             self._dispatch_jit = jax.jit(_impl)
         else:
@@ -252,8 +261,11 @@ class RuntimeBackend:
                 with_corpus=self._corpus is not None)
 
             def _impl(hp, store_ids, payload, q, ex, m):
-                self.traces += 1  # runs at trace time only
-                return step(hp, store_ids, payload, q, ex, m)
+                # runs at trace time only
+                with span_or_null(self.tracer, "serve/retrace",
+                                  pad=q.shape[0]):
+                    self.traces += 1
+                    return step(hp, store_ids, payload, q, ex, m)
 
             self._dispatch_jit = jax.jit(_impl, static_argnums=(5,))
 
@@ -425,7 +437,8 @@ class RuntimeBackend:
         return self._cost
 
     def dispatch_async(self, q_pad: np.ndarray, ex_pad: np.ndarray,
-                       m: int) -> PendingDispatch:
+                       m: int, *, batch: int = -1,
+                       rows: int | None = None) -> PendingDispatch:
         """Launch one batch through the jit'd step WITHOUT waiting.
 
         JAX dispatches asynchronously, so this returns (host -> device
@@ -433,12 +446,15 @@ class RuntimeBackend:
         computes; the returned `PendingDispatch` finishes the batch —
         `wait()` for the host-side results, `ready()` to probe without
         blocking.  Keeping stage and wait apart is what lets the
-        frontend hold `pipeline_depth` batches in flight."""
+        frontend hold `pipeline_depth` batches in flight.  `batch` (the
+        frontend's dispatch seq) and `rows` (live rows, all of `q_pad`
+        when None) tag this dispatch's spans."""
         import jax.numpy as jnp
 
         distributed = self._rt.is_distributed
-        with span_or_null(self.tracer, "serve/stage",
-                          pad=int(q_pad.shape[0])):
+        pad = int(q_pad.shape[0])
+        with span_or_null(self.tracer, "serve/stage", batch=batch,
+                          rows=pad if rows is None else rows, pad=pad):
             if not distributed:
                 payload = (
                     self._corpus if self._corpus is not None
@@ -448,7 +464,7 @@ class RuntimeBackend:
                     self._hp, self._store.ids, payload,
                     jnp.asarray(q_pad, jnp.float32), jnp.asarray(ex_pad), m,
                 )
-                return PendingDispatch(self, raw, None, m, False)
+                return PendingDispatch(self, raw, None, m, False, batch)
 
             if m > self.max_m:
                 raise ValueError(
@@ -463,7 +479,8 @@ class RuntimeBackend:
                 args += (self._replicas[0], self._replicas[1],
                          jnp.asarray(self._live, jnp.int32))
             raw = self._dispatch_jit(*args, q)
-            return PendingDispatch(self, raw, np.asarray(ex_pad), m, True)
+            return PendingDispatch(self, raw, np.asarray(ex_pad), m, True,
+                                   batch)
 
     def _finish(self, raw, ex_pad, m, distributed):
         """Host-side tail of a dispatch (called by `PendingDispatch.wait`
@@ -791,25 +808,29 @@ class RetrievalFrontend:
 
     # -- the pipelined step machine -------------------------------------------
 
-    def _install_updates(self) -> None:
+    def _install_updates(self, tr, seq: int) -> None:
         """Stage boundary hook: install any churn updates the background
         writer has prepared (repro.serve.writer).  Runs on the serving
         thread, BETWEEN dispatches — the writer never touches the
         backend from its own thread."""
         if self.writer is not None:
-            self.writer.install(self)
+            with span_or_null(tr, "serve/install", batch=seq):
+                self.writer.install(self)
 
     def _stage_batch(self) -> None:
         """Intake up to `max_batch` ring rows and stage them onto the
         device dispatch queue (async — returns before the batch
         computes).  Caller guarantees ring rows exist and the pipeline
-        has a free slot."""
-        self._install_updates()
+        has a free slot.  Every span of the dispatch carries its seq as
+        `batch`."""
         obs = self.obs
         tr = obs.tracer if obs is not None else None
+        seq = self._dispatch_seq
+        self._dispatch_seq += 1
+        self._install_updates(tr, seq)
         cap = self.cfg.queue_capacity
         n = min(self._size, self.cfg.max_batch)
-        with span_or_null(tr, "serve/intake", n=n):
+        with span_or_null(tr, "serve/intake", batch=seq, n=n):
             idx = (self._head + np.arange(n)) % cap
             q = self._ring_q[idx].copy()
             ex = self._ring_ex[idx].copy()
@@ -819,7 +840,7 @@ class RetrievalFrontend:
             self._head = (self._head + n) % cap
             self._size -= n
 
-        with span_or_null(tr, "serve/enqueue", rows=n):
+        with span_or_null(tr, "serve/enqueue", batch=seq, rows=n):
             pad = dispatch_pad(n, self.backend.min_batch)
             mq = np.zeros((pad, q.shape[1]), np.float32)
             mex = np.full((pad,), NO_EXCLUDE, np.int32)
@@ -827,8 +848,7 @@ class RetrievalFrontend:
             mex[:n] = ex
             t_stage = time.perf_counter()
             queue_us = (t_stage - t_sub[:n]) * 1e6
-            for us in queue_us:
-                self.stats.record_queue_time(us)
+            self.stats.record_queue_times(queue_us)
             if self._h_queue is not None:
                 # bulk observe: per-row Python observes are measurable
                 # against the obs_overhead budget
@@ -838,10 +858,9 @@ class RetrievalFrontend:
 
         gen = self.backend.generation
         t0 = time.perf_counter()
-        pending = self.backend.dispatch_async(mq, mex, self.cfg.m)
+        pending = self.backend.dispatch_async(mq, mex, self.cfg.m, batch=seq,
+                                              rows=n)
         stage_us = (time.perf_counter() - t0) * 1e6
-        seq = self._dispatch_seq
-        self._dispatch_seq += 1
         self._inflight.append(_InflightBatch(
             pending, tickets, keys, t_sub, mq, mex, n, pad, gen, seq,
             stage_us,
@@ -928,12 +947,13 @@ class RetrievalFrontend:
         which is what the pipelined==synchronous equivalence test pins
         down.  (The open-loop serving path uses `pump`, which does probe.)
 
-        With obs installed the stages emit spans (intake -> enqueue ->
-        stage -> compute -> reap -> respond) and every served query +
-        every backend dispatch appends a `QueryRecord` to the flight
-        recorder — dispatch records carry the step's EXACT `StepStats`,
-        query records their batch's per-row share plus the latency
-        breakdown.
+        The stages emit profiler spans (install -> intake -> enqueue ->
+        stage -> compute -> fetch -> reap -> respond), each tagged with
+        the dispatch seq as `batch`; with obs installed the spans also
+        land in its tracer's ring, and every served query + every backend
+        dispatch appends a `QueryRecord` to the flight recorder —
+        dispatch records carry the step's EXACT `StepStats`, query
+        records their batch's per-row share plus the latency breakdown.
         """
         done = 0
         staged = False

@@ -41,6 +41,8 @@ class ServeStats:
     batches: int = 0         # backend dispatches
     dispatched: int = 0      # cache-miss queries sent to the backend
     padded: int = 0          # dead rows added by pow-2 batch padding
+    queued_us: float = 0.0   # ring wait summed over dispatched rows
+    #                          (submit -> their batch's stage)
     dropped_probes: int = 0  # router overflow across all dispatches
     # Table-1 cost accumulators (charged per dispatched query)
     messages: float = 0.0
@@ -77,13 +79,18 @@ class ServeStats:
             self._t_first = now
         self.ring_full += 1
 
-    def record_queue_time(self, queue_us: float) -> None:
-        """Time one request spent in the ring before its batch was staged
-        onto the device queue."""
+    def record_queue_times(self, queue_us: np.ndarray) -> None:
+        """Time each row of one staged batch spent in the ring before the
+        batch was staged onto the device queue (one call per batch)."""
+        w = self.latency_window
         if self._queue is None:
-            self._queue = np.empty((self.latency_window,), np.float64)
-        self._queue[self.staged % self.latency_window] = queue_us
-        self.staged += 1
+            self._queue = np.empty((w,), np.float64)
+        n = queue_us.size
+        self.queued_us += float(queue_us.sum())
+        keep = queue_us[-w:]  # a batch wider than the ring keeps its tail
+        self._queue[(self.staged + n - keep.size + np.arange(keep.size))
+                    % w] = keep
+        self.staged += n
 
     def record_done(self, latency_us: float, *, hit: bool) -> None:
         if hit:

@@ -2,11 +2,14 @@
 
 Pins the obs contracts:
   * registry: label-set aggregation, idempotent registration (kind
-    mismatch is a TypeError), JSON snapshot schema, Prometheus text
-    format, bucket-resolution histogram quantiles;
+    mismatch is a TypeError), JSON snapshot schema, bucket-resolution
+    histogram quantiles;
   * tracer: span nesting via plain stack, stopwatch semantics with
     recording disabled, Chrome-trace JSON that round-trips and carries
     the required event keys;
+  * profiler spans: with obs off, every dispatch leaves its stage,
+    compute, fetch, reap and respond spans, tagged with one `batch`, on
+    the profiler trace's host plane;
   * flight recorder: bounded ring, drop-spike auto dump (dispatch/epoch
     records only), anomaly snapshots preserving the ring, `total()`
     accounting over direct fields and `extra` entries;
@@ -132,17 +135,17 @@ def test_snapshot_schema_and_prometheus_text():
     hs = snap["h"]["samples"][0]
     assert hs["count"] == 1 and hs["sum"] == 3.0
     assert hs["buckets"] == {"10": 1, "+Inf": 1}  # cumulative
-    json.dumps(snap)  # JSON-able end to end
+    assert json.loads(json.dumps(snap)) == snap  # JSON-able end to end
 
-    text = reg.prometheus_text()
-    assert "# HELP c_total help text" in text
-    assert "# TYPE c_total counter" in text
-    assert 'c_total{node="0"} 4' in text
-    assert "g 1.5" in text
-    assert 'h_bucket{le="10"} 1' in text
-    assert 'h_bucket{le="+Inf"} 1' in text
-    assert "h_sum 3" in text and "h_count 1" in text
-    assert text.endswith("\n")
+    # the snapshot is the one export: label sets come out sorted, each
+    # histogram series with its own cumulative buckets
+    reg.histogram("h", buckets=(10.0,)).observe(30.0, node="1")
+    reg.counter("c_total").inc(1, node="-1")
+    snap = reg.snapshot()
+    assert [s["labels"] for s in snap["c_total"]["samples"]] == [
+        {"node": "-1"}, {"node": "0"}]
+    assert [s["buckets"] for s in snap["h"]["samples"]] == [
+        {"10": 1, "+Inf": 1}, {"10": 0, "+Inf": 1}]
 
 
 # -----------------------------------------------------------------------------
@@ -171,13 +174,16 @@ def test_disabled_tracer_still_times_but_records_nothing():
         pass
     assert sp.duration_s >= 0.0
     assert tr.events() == []
-    tr.instant("marker")
-    assert tr.events() == []
+    with tr.span("outer"), tr.span("inner"):
+        pass
+    assert tr.events() == [] and tr.depth == 0
 
 
 def test_span_or_null_without_tracer_yields_null_context():
-    with span_or_null(None, "anything") as sp:
-        assert sp is None
+    # without a tracer the span is the bare profiler annotation: nothing
+    # is timed on the host or recorded into any ring
+    with span_or_null(None, "anything", batch=3) as sp:
+        assert not isinstance(sp, Span)
     tr = Tracer()
     with span_or_null(tr, "named", n=3) as sp:
         assert isinstance(sp, Span)
@@ -206,20 +212,23 @@ def test_tracer_ring_is_bounded():
 def test_chrome_trace_round_trips_with_required_keys(tmp_path):
     tr = Tracer()
     with tr.span("stage", cat="serve", rows=7):
-        pass
-    tr.instant("blip")
+        with tr.span("inner"):
+            pass
     path = tmp_path / "trace.json"
-    tr.export(str(path))
+    path.write_text(json.dumps(tr.to_chrome_trace()))
     doc = json.loads(path.read_text())
     assert doc["displayTimeUnit"] == "ms"
     evs = doc["traceEvents"]
     assert len(evs) == 2
     for ev in evs:
-        assert {"name", "cat", "ph", "ts", "pid", "tid"} <= set(ev)
-    complete = next(e for e in evs if e["ph"] == "X")
-    assert complete["dur"] >= 0.0 and complete["args"]["rows"] == 7
-    instant = next(e for e in evs if e["ph"] == "i")
-    assert instant["s"] == "t"
+        assert {"name", "cat", "ph", "ts", "dur", "pid", "tid"} <= set(ev)
+        assert ev["ph"] == "X" and ev["dur"] >= 0.0
+    stage = next(e for e in evs if e["name"] == "stage")
+    assert stage["args"] == {"rows": 7, "depth": 0}
+    inner = next(e for e in evs if e["name"] == "inner")
+    assert inner["args"]["depth"] == 1
+    assert stage["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= stage["ts"] + stage["dur"]
 
 
 # -----------------------------------------------------------------------------
@@ -359,7 +368,68 @@ def test_frontend_obs_records_agree_with_stats():
         assert r.probes_issued == d.probes_issued // d.batch_size
     span_names = {e[1] for e in obs.tracer.events()}
     assert {"serve/intake", "serve/enqueue", "serve/stage", "serve/compute",
-            "serve/reap", "serve/respond"} <= span_names
+            "serve/fetch", "serve/reap", "serve/respond"} <= span_names
+
+
+def _profiled_serve_spans(tmp_path, fn):
+    """Run fn() under the jax profiler; the `serve/*` events of the trace's
+    host planes as (name, start_ns, stats), by start."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = [(e.name, e.start_ns, dict(e.stats))
+           for plane in ProfileData.from_file(path).planes
+           if plane.name.startswith("/host:")
+           for line in plane.lines for e in line.events
+           if e.name.startswith("serve/")]
+    return sorted(out, key=lambda e: e[1])
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_stage_spans_reach_the_profiler_without_obs(tmp_path, depth):
+    """With obs off (how the chip benchmark builds its frontend) every
+    dispatch still leaves its stage spans on the profiler's host plane,
+    each tagged with the dispatch seq as `batch`, in pipeline order."""
+    emb, engine = _make_engine()
+    fe = RetrievalFrontend(
+        RuntimeBackend(engine),
+        FrontendConfig(m=M, max_batch=8, queue_capacity=64, cache=False,
+                       pipeline_depth=depth))
+    for n in (1, 2, 4, 8):   # compile every shape outside the trace
+        fe.search(emb[:n])
+    warm = fe.stats.batches
+
+    def serve():
+        for q in emb[:29]:
+            fe.submit(q)
+            fe.pump()
+        fe.flush()
+
+    spans = _profiled_serve_spans(tmp_path, serve)
+    by_batch: dict[int, list] = {}
+    for name, _, stats in spans:
+        by_batch.setdefault(stats["batch"], []).append((name, stats))
+    assert sorted(by_batch) == list(range(warm, fe.stats.batches))
+    for seq, evs in by_batch.items():
+        assert [n for n, _ in evs] == [
+            "serve/intake", "serve/enqueue", "serve/stage", "serve/compute",
+            "serve/fetch", "serve/reap", "serve/respond"], seq
+        stage = dict(evs)["serve/stage"]
+        assert 1 <= stage["rows"] <= stage["pad"] <= 8
+        assert stage["pad"] & (stage["pad"] - 1) == 0   # the pow-2 grid
+    rows = sum(dict(evs)["serve/stage"]["rows"] for evs in by_batch.values())
+    assert rows == 29
 
 
 def test_cache_hits_become_hit_records():

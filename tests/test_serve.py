@@ -414,6 +414,55 @@ def test_telemetry_latency_window_is_bounded():
     assert s.percentile(50) == pytest.approx(7.5)
 
 
+def test_queue_times_match_per_row_ring():
+    """One `record_queue_times` call per batch keeps exactly the ring a
+    per-row loop keeps, a batch wider than the ring included, and sums
+    every row into `queued_us`."""
+    window = 5
+    s = ServeStats(latency_window=window)
+    ring = np.empty((window,), np.float64)
+    staged = 0
+    for batch in ([1.0, 2.0, 3.0], [4.0, 5.0, 6.0, 7.0], list(range(8, 20))):
+        s.record_queue_times(np.asarray(batch, np.float64))
+        for us in batch:   # the per-row reference
+            ring[staged % window] = us
+            staged += 1
+        np.testing.assert_array_equal(s._queue, ring)
+    assert s.staged == staged == 19
+    assert s.queued_us == sum(range(1, 20))
+    assert s.queue_percentile(50) == np.percentile(ring, 50)
+
+
+def test_queued_us_sums_ring_wait_of_live_rows(monkeypatch):
+    """`ServeStats.queued_us` is the sum over dispatched rows of stage
+    time minus submit time, on a hand-driven frontend and clock."""
+    import time
+
+    emb, engine, _ = _make_engine()
+    fe = RetrievalFrontend(
+        RuntimeBackend(engine),
+        FrontendConfig(m=M, max_batch=4, queue_capacity=16, cache=False))
+    clock = [100.0]
+    monkeypatch.setattr(time, "perf_counter", lambda: clock[0])
+    expect = 0.0
+    for submits, stage_at in (([100.0, 100.5, 101.0], 102.0),
+                              ([103.0, 103.25, 103.5, 103.75, 104.0], 106.0)):
+        for t, q in zip(submits, emb):
+            clock[0] = t
+            fe.submit(q)
+        clock[0] = stage_at
+        while fe.pending:
+            live = min(fe.pending, fe.cfg.max_batch)
+            expect += sum(stage_at - t for t in submits[-fe.pending:]
+                          [:live]) * 1e6
+            fe.step()
+    fe.flush()
+    assert fe.stats.dispatched == 8
+    assert fe.stats.queued_us == pytest.approx(expect)
+    assert expect == pytest.approx((2.0 + 1.5 + 1.0 + 3.0 + 2.75 + 2.5
+                                    + 2.25 + 2.0) * 1e6)
+
+
 # -----------------------------------------------------------------------------
 # mesh-step backend (single device, 1-shard — tier-1)
 # -----------------------------------------------------------------------------
